@@ -252,8 +252,7 @@ def _spec_for(tl):
 def _region_audit(domains: bool) -> tuple:
     """(jaxpr stats,) of the fused single-worker region run."""
     import jax
-    import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     from repro.core import device_pipeline as dp
     from repro.core.device_pipeline import DeviceTimeline
@@ -262,12 +261,9 @@ def _region_audit(domains: bool) -> tuple:
     spec = _spec_for(tl)
     dtl = DeviceTimeline.from_timelines([tl])
     with enable_x64():
-        fn = dp._region_run_fn(_CHUNK, spec, dtl.num_regions, False,
-                               dtl.grid_k)
-        args = (*dtl.arrays(), jax.random.PRNGKey(0),
-                jnp.float64(10e-3), jnp.float64(200e-6),
-                jnp.float64(dtl.t_end), jnp.float64(0.0),
-                jnp.float64(55.0), jnp.int32(2))
+        fn, args = dp.region_pipeline_call(dtl, spec, period=10e-3,
+                                           chunk_size=_CHUNK,
+                                           use_pallas=False)
         jaxpr = jax.make_jaxpr(fn)(*args)
     return (audit_jaxpr(jaxpr),)
 
@@ -293,7 +289,7 @@ def _combo_audit(domains: bool) -> tuple:
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     from repro.core import device_pipeline as dp
     from repro.core.device_pipeline import DeviceTimeline
@@ -351,7 +347,7 @@ def _fold_audit(domains: bool) -> tuple:
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     from repro.core import device_pipeline as dp
 
@@ -490,16 +486,15 @@ def _collective_audit(kind: str) -> JaxprStats:
     import jax
     import jax.numpy as jnp
     from functools import partial
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
     from repro.core import exchange
     from repro.launch.mesh import make_exchange_mesh
 
     axis = "hosts"
     mesh = make_exchange_mesh(1, axis=axis)
-    smap = partial(shard_map, mesh=mesh, in_specs=P(axis), out_specs=P(),
+    smap = partial(jax.shard_map, mesh=mesh, in_specs=P(axis), out_specs=P(),
                    check_vma=False)
     cap, chan, width = 8, 3, 2
     with enable_x64():

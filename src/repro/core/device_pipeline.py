@@ -79,7 +79,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from repro.core.sensors import (DEFAULT_IDLE_POWER, SensorSpec,
                                 _TraceSensorBase, idle_channel)
@@ -92,7 +92,8 @@ from repro.kernels.sample_attr.ops import make_carry_update
 
 __all__ = [
     "DeviceTimeline", "PipelineResult", "chunk_sample_times",
-    "num_chunks", "num_channels", "run_region_pipeline",
+    "num_chunks", "num_channels", "region_pipeline_call",
+    "run_region_pipeline",
     "run_combo_pipeline", "reference_region_pipeline",
     "reference_combo_pipeline",
 ]
@@ -531,6 +532,35 @@ def _region_run_fn(chunk_size: int, spec: SensorSpec, num_regions: int,
     return jax.jit(run)
 
 
+def region_pipeline_call(dtl: DeviceTimeline, spec: SensorSpec, *,
+                         period: float, jitter: float = 200e-6,
+                         seed: int = 0, chunk_size: int = DEFAULT_CHUNK,
+                         overhead_per_sample: float = 0.0,
+                         idle_power: float = DEFAULT_IDLE_POWER,
+                         use_pallas: bool | None = None):
+    """``(fn, args)``: the jitted fused run and the arguments
+    :func:`run_region_pipeline` calls it with. Call it under
+    ``enable_x64`` — or ``fn.lower(*args)`` to inspect the compiled
+    program (e.g. that the Pallas reduction is in it)."""
+    _check_sampling_args(spec, period, jitter)
+    _check_spec_domains(spec, dtl)
+    if dtl.num_workers != 1:
+        raise ValueError(f"region pipeline is single-worker; got "
+                         f"W={dtl.num_workers} (use run_combo_pipeline)")
+    if use_pallas is None:
+        use_pallas = jax.default_backend() == "tpu"
+    frac = min(overhead_per_sample / period, 1.0) \
+        if overhead_per_sample > 0.0 else 0.0
+    k_chunks = num_chunks(dtl.t_end, period, chunk_size)
+    fn = _region_run_fn(chunk_size, spec, dtl.num_regions,
+                        bool(use_pallas), dtl.grid_k)
+    args = (*dtl.arrays(), jax.random.PRNGKey(seed),
+            jnp.float64(period), jnp.float64(jitter),
+            jnp.float64(dtl.t_end), jnp.float64(frac),
+            jnp.float64(idle_power), jnp.int32(k_chunks))
+    return fn, args
+
+
 def run_region_pipeline(dtl: DeviceTimeline, spec: SensorSpec, *,
                         period: float, jitter: float = 200e-6, seed: int = 0,
                         chunk_size: int = DEFAULT_CHUNK,
@@ -546,24 +576,12 @@ def run_region_pipeline(dtl: DeviceTimeline, spec: SensorSpec, *,
     but equally valid jitter process for the same seed);
     :func:`reference_region_pipeline` is the exact numpy mirror.
     """
-    _check_sampling_args(spec, period, jitter)
-    _check_spec_domains(spec, dtl)
-    if dtl.num_workers != 1:
-        raise ValueError(f"region pipeline is single-worker; got "
-                         f"W={dtl.num_workers} (use run_combo_pipeline)")
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    frac = min(overhead_per_sample / period, 1.0) \
-        if overhead_per_sample > 0.0 else 0.0
     with enable_x64():
-        k_chunks = num_chunks(dtl.t_end, period, chunk_size)
-        fn = _region_run_fn(chunk_size, spec, dtl.num_regions,
-                            bool(use_pallas), dtl.grid_k)
-        counts, psum, psumsq, n = fn(
-            *dtl.arrays(), jax.random.PRNGKey(seed),
-            jnp.float64(period), jnp.float64(jitter),
-            jnp.float64(dtl.t_end), jnp.float64(frac),
-            jnp.float64(idle_power), jnp.int32(k_chunks))
+        fn, args = region_pipeline_call(
+            dtl, spec, period=period, jitter=jitter, seed=seed,
+            chunk_size=chunk_size, overhead_per_sample=overhead_per_sample,
+            idle_power=idle_power, use_pallas=use_pallas)
+        counts, psum, psumsq, n = fn(*args)
         n = int(n)
     if n == 0:
         raise ValueError("run too short for sampling period")

@@ -358,11 +358,11 @@ def collective_reduce(shards: Sequence[StreamingAggregator |
     host-local id spaces, not summable) and every host folds the same
     ordered union merge, so results are identical everywhere.
     """
-    from jax.experimental import enable_x64
+    import jax
+    from jax import enable_x64
     from jax.sharding import PartitionSpec as P
     from functools import partial
 
-    from repro.compat import shard_map
     from repro.launch.mesh import make_exchange_mesh
 
     if not shards:
@@ -407,7 +407,7 @@ def collective_reduce(shards: Sequence[StreamingAggregator |
                 f"mixed bounded-state configs across collective shards: "
                 f"{sorted(configs, key=repr)}")
         combo_k, combo_hr = configs.pop()
-    smap = partial(shard_map, mesh=mesh, in_specs=P(axis), out_specs=P(),
+    smap = partial(jax.shard_map, mesh=mesh, in_specs=P(axis), out_specs=P(),
                    check_vma=False)
 
     # jax's default 32-bit mode would truncate int64 counts and round

@@ -29,7 +29,7 @@ import dataclasses
 import numpy as np
 
 __all__ = ["HardwareSpec", "PowerModelParams", "PowerModel", "TPU_V5E",
-           "POWER_DOMAINS"]
+           "DEVICE_PEAKS", "hardware_for", "POWER_DOMAINS"]
 
 # The power-rail domain axis: the decomposition the activity model already
 # computes internally (per-resource utilization terms) before summing to
@@ -54,6 +54,10 @@ class HardwareSpec:
     hbm_bytes: int              # HBM capacity per chip
 
 
+# Published per-chip peaks (Google Cloud documentation, "TPU v5e"):
+# 197 TFLOP/s bf16, 16 GB of HBM at 819 GB/s, 1,600 Gbit/s of
+# chip-to-chip interconnect (4 links × 50 GB/s). vmem_bytes is the
+# compiler's default scoped-VMEM limit, not the physical VMEM size.
 TPU_V5E = HardwareSpec(
     name="tpu-v5e",
     peak_flops_bf16=197e12,
@@ -63,6 +67,22 @@ TPU_V5E = HardwareSpec(
     vmem_bytes=16 * 1024 * 1024,
     hbm_bytes=16 * 1024**3,
 )
+
+# Peaks by ``jax.devices()[0].device_kind``. A device that is not listed
+# has no peaks here: :func:`hardware_for` raises rather than defaulting.
+DEVICE_PEAKS: dict[str, HardwareSpec] = {
+    "TPU v5 lite": TPU_V5E,
+}
+
+
+def hardware_for(device_kind: str) -> HardwareSpec:
+    """Published peaks of the chip JAX reports as ``device_kind``."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(DEVICE_PEAKS)}"
+                       ) from None
 
 
 @dataclasses.dataclass(frozen=True)

@@ -140,11 +140,11 @@ class EnergyProfiler:
     def _resolve_pipeline(self, pipeline: str, aggregate_fn) -> bool:
         """True → fused device pipeline; False → host-numpy chunk loop.
 
-        ``auto`` prefers the device pipeline whenever JAX is importable
-        and no explicit per-chunk ``aggregate_fn`` was plugged in (a
-        custom kernel plug implies the host chunk seam), falling back to
-        the host path when the device path's preconditions don't hold
-        (no jax, or jitter > period breaks its monotone sample clock).
+        ``auto`` prefers the device pipeline unless an explicit
+        per-chunk ``aggregate_fn`` was plugged in (a custom kernel plug
+        implies the host chunk seam) or jitter > period (which breaks the
+        device path's monotone sample clock). A device pipeline that
+        fails to load raises; it never degrades to the host path.
         """
         if pipeline not in ("auto", "device", "host"):
             raise ValueError(f"pipeline must be auto|device|host; "
@@ -156,16 +156,8 @@ class EnergyProfiler:
                 "aggregate_fn plugs the host chunk seam and would be "
                 "silently ignored by the device pipeline; use "
                 "pipeline=\"host\" (or drop aggregate_fn)")
-        if pipeline == "auto" and (aggregate_fn is not None
-                                   or self.jitter > self.period):
-            return False
-        try:
-            import repro.core.device_pipeline  # noqa: F401
-        except ImportError:
-            if pipeline == "device":
-                raise
-            return False
-        return True
+        return not (pipeline == "auto" and (aggregate_fn is not None
+                                            or self.jitter > self.period))
 
     def profile_timeline_streaming(self, tl: Timeline, *,
                                    sensor: str = "rapl",

@@ -7,15 +7,24 @@ statistics become MXU matmuls:
 
     counts += 1ᵀ · onehot      psum += powᵀ · onehot      psumsq += (pow²)ᵀ · onehot
 
+Layout (Mosaic-friendly, everything 2-D): ids and powers stream as
+``[1, block_n]`` lane-major blocks. Each step builds the statistic rows
+``[8, block_n]`` = (1, pow, pow², 0-padding) and the transposed tile-local
+one-hot ``[block_r, block_n]`` (sublane iota == ids), and issues ONE
+``[8, block_n] · [block_r, block_n]ᵀ`` MXU matmul at full f32 precision
+into an ``[8, block_r]`` accumulator block.
+
 Grid: (region tiles, sample blocks), sample axis innermost. Each region
-tile's [block_r] accumulators live in the output blocks (same block across
-the whole inner sweep → VMEM-resident); sample blocks stream HBM→VMEM.
-The region axis is tiled so num_regions is unbounded: R > 2048 (e.g. the
-10⁴–10⁵ multi-worker combination space) no longer overflows VMEM — the
-default 1024×2048 one-hot tile (1024×2048×4B = 8 MB) is the VMEM budget
-regardless of R. Samples are re-streamed once per region tile; the
-region-tile loop is the classic reduction-tiling tradeoff (R/block_r ×
-sample traffic for O(block_r) on-chip state).
+tile's accumulator block is the output block (same block across the
+whole inner sweep → VMEM-resident); sample blocks stream HBM→VMEM. The
+region axis is tiled so num_regions is unbounded: R > block_r (e.g. the
+10⁴–10⁵ multi-worker combination space) never overflows VMEM — the
+one-hot tile (block_r × block_n × 4 B, 8 MB at the defaults, plus its
+iota/compare temporaries) is the VMEM budget regardless of R; the v5e
+compiler accepts it within the default scoped-VMEM limit
+(``tests/test_chip_compile.py``). Samples are re-streamed once per region
+tile; the region-tile loop is the classic reduction-tiling tradeoff
+(R/block_r × sample traffic for O(block_r) on-chip state).
 """
 
 from __future__ import annotations
@@ -24,33 +33,38 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 DEFAULT_BLOCK_N = 1024
 DEFAULT_BLOCK_R = 2048
+_ROWS = 8       # statistic rows (1, pow, pow², zero padding): one sublane tile
 
 
-def _kernel(ids_ref, pow_ref, counts_ref, psum_ref, psumsq_ref, *,
-            block_r: int):
+def _kernel(ids_ref, pow_ref, out_ref, *, block_r: int):
     j = pl.program_id(0)   # region tile (outer)
-    i = pl.program_id(1)   # sample block (inner; accumulators stay resident)
+    i = pl.program_id(1)   # sample block (inner; accumulator stays resident)
 
     @pl.when(i == 0)
     def _init():
-        counts_ref[...] = jnp.zeros_like(counts_ref)
-        psum_ref[...] = jnp.zeros_like(psum_ref)
-        psumsq_ref[...] = jnp.zeros_like(psumsq_ref)
+        out_ref[...] = jnp.zeros_like(out_ref)
 
-    ids = ids_ref[...]                                  # [bn] int32
-    pw = pow_ref[...].astype(jnp.float32)               # [bn]
-    # Tile-local one-hot via broadcasted iota compare (2D iota: TPU-legal).
-    # Ids outside this tile (and -1 padding) match no column → zero rows.
+    ids = ids_ref[...]                                  # [1, bn] int32
+    pw = pow_ref[...]                                   # [1, bn] f32
+    bn = ids.shape[1]
+    row = jax.lax.broadcasted_iota(jnp.int32, (_ROWS, bn), 0)
+    stats = jnp.where(row == 0, 1.0,
+                      jnp.where(row == 1, pw,
+                                jnp.where(row == 2, pw * pw, 0.0)))
+    # Tile-local one-hot, transposed: region on sublanes, sample on lanes.
+    # Ids outside this tile (and -1 padding) match no row → zero columns.
     local = ids - j * block_r
-    iota = jax.lax.broadcasted_iota(jnp.int32, (ids.shape[0], block_r), 1)
-    onehot = (local[:, None] == iota).astype(jnp.float32)  # [bn, block_r]
-    counts_ref[...] += jnp.sum(onehot, axis=0)
-    psum_ref[...] += pw @ onehot
-    psumsq_ref[...] += (pw * pw) @ onehot
+    onehot_t = (jax.lax.broadcasted_iota(jnp.int32, (block_r, bn), 0)
+                == local).astype(jnp.float32)           # [block_r, bn]
+    out_ref[...] += jax.lax.dot_general(
+        stats, onehot_t, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)             # [8, block_r]
 
 
 def sample_attr_pallas(region_ids: jnp.ndarray, powers: jnp.ndarray,
@@ -61,7 +75,8 @@ def sample_attr_pallas(region_ids: jnp.ndarray, powers: jnp.ndarray,
 
     ``block_r`` tiles the region axis (default: min(num_regions, 2048));
     any ``num_regions`` is supported — the region space is padded up to a
-    multiple of ``block_r`` and the outputs sliced back.
+    multiple of ``block_r`` and the outputs sliced back. Returns float32
+    (counts, Σpow, Σpow²), each [num_regions].
     """
     if block_r is None:
         block_r = min(num_regions, DEFAULT_BLOCK_R)
@@ -75,19 +90,18 @@ def sample_attr_pallas(region_ids: jnp.ndarray, powers: jnp.ndarray,
     num_r_padded = num_regions + r_pad
     grid = (num_r_padded // block_r, region_ids.shape[0] // block_n)
 
-    out_shape = [jax.ShapeDtypeStruct((num_r_padded,), jnp.float32)] * 3
-    out_specs = [pl.BlockSpec((block_r,), lambda j, i: (j,))] * 3
-    counts, psum, psumsq = pl.pallas_call(
+    # Block indices stay int32 when traced under enable_x64 (the fused
+    # pipeline's carry): a bare 0 would widen to an int64 index, which
+    # Mosaic cannot lower.
+    zero = np.int32(0)
+    sample_spec = pl.BlockSpec((1, block_n), lambda j, i: (zero, i))
+    out = pl.pallas_call(
         functools.partial(_kernel, block_r=block_r),
         grid=grid,
-        in_specs=[pl.BlockSpec((block_n,), lambda j, i: (i,)),
-                  pl.BlockSpec((block_n,), lambda j, i: (i,))],
-        out_specs=out_specs,
-        out_shape=out_shape,
+        in_specs=[sample_spec, sample_spec],
+        out_specs=pl.BlockSpec((_ROWS, block_r), lambda j, i: (zero, j)),
+        out_shape=jax.ShapeDtypeStruct((_ROWS, num_r_padded), jnp.float32),
         interpret=interpret,
-    )(region_ids, powers.astype(jnp.float32))
-    if r_pad:
-        counts = counts[:num_regions]
-        psum = psum[:num_regions]
-        psumsq = psumsq[:num_regions]
-    return counts, psum, psumsq
+    )(region_ids.reshape(1, -1), powers.astype(jnp.float32).reshape(1, -1))
+    return (out[0, :num_regions], out[1, :num_regions],
+            out[2, :num_regions])

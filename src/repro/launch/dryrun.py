@@ -203,9 +203,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool,
                 mem_compiled = compiled
 
     mem = mem_compiled.memory_analysis()
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):   # jax 0.4.x: one dict per program
-        cost = cost[0] if cost else {}
+    cost = compiled.cost_analysis() or {}
     hlo_text = compiled.as_text()
 
     n_tokens = shape.global_batch * (shape.seq_len if not shape.is_decode
@@ -214,12 +212,12 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool,
         getattr(mem, "argument_size_in_bytes", 0)
     report = roofline_terms(
         arch=arch, shape=shape_name, mesh_name=mesh_name, chips=chips,
-        cost_analysis=cost or {}, hlo_text=hlo_text,
+        cost_analysis=cost, hlo_text=hlo_text,
         n_params_active=cfg.active_param_count(), n_tokens=n_tokens,
         training=training, bytes_per_device=int(bytes_per_device))
     row = report.row()
-    row["flops_per_device"] = float((cost or {}).get("flops", 0.0))
-    row["hbm_bytes_per_device"] = float((cost or {}).get("bytes accessed", 0.0))
+    row["flops_per_device"] = float(cost.get("flops", 0.0))
+    row["hbm_bytes_per_device"] = float(cost.get("bytes accessed", 0.0))
     row["coll_bytes_per_device"] = int(report.collective_bytes)
     row["mem_analysis"] = str(mem)
     row["warnings"] = rules.warnings

@@ -5,11 +5,9 @@ importing this module never touches jax device state. The dry-run launcher
 sets XLA_FLAGS=--xla_force_host_platform_device_count=512 before any jax
 import; ordinary smoke tests and benches see 1 device.
 
-``jax.sharding.AxisType`` (and the matching ``axis_types=`` kwarg of
-``jax.make_mesh``) only exists from jax 0.5.x; on 0.4.x meshes are
-implicitly Auto-typed. :func:`axis_types_kwargs` returns the kwarg dict
-when supported and ``{}`` otherwise, and :func:`make_mesh_compat` is the
-version-portable constructor every caller (launchers, tests) should use.
+Every mesh is built with Auto axis types (:func:`make_auto_mesh`): the
+sharding rules place arrays with ``with_sharding_constraint`` and leave
+propagation to the compiler.
 """
 
 from __future__ import annotations
@@ -17,40 +15,27 @@ from __future__ import annotations
 from typing import Sequence
 
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["axis_types_kwargs", "make_mesh_compat", "make_production_mesh",
+__all__ = ["make_auto_mesh", "make_production_mesh",
            "make_small_mesh", "make_exchange_mesh", "dp_axes_for"]
 
 
-def axis_types_kwargs(n: int) -> dict:
-    """``axis_types=`` kwarg for ``jax.make_mesh``, empty pre-jax-0.5.
-
-    jax 0.4.x raises AttributeError for ``jax.sharding.AxisType`` (its
-    deprecation shim) and ``jax.make_mesh`` has no ``axis_types`` kwarg;
-    an Auto-typed mesh is the implicit (and only) behavior there, so
-    omitting the kwarg is semantically identical.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
-
-
-def make_mesh_compat(shape: Sequence[int], axes: Sequence[str]):
-    """``jax.make_mesh`` with Auto axis types on every jax version."""
+def make_auto_mesh(shape: Sequence[int], axes: Sequence[str]):
+    """``jax.make_mesh`` with every axis Auto-typed."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **axis_types_kwargs(len(axes)))
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_auto_mesh(shape, axes)
 
 
 def make_small_mesh(data: int = 1, model: int = 1):
     """Tiny mesh for CPU tests (device count permitting)."""
-    return make_mesh_compat((data, model), ("data", "model"))
+    return make_auto_mesh((data, model), ("data", "model"))
 
 
 def make_exchange_mesh(n_hosts: int | None = None, axis: str = "hosts"):
@@ -61,7 +46,7 @@ def make_exchange_mesh(n_hosts: int | None = None, axis: str = "hosts"):
     """
     if n_hosts is None:
         n_hosts = jax.device_count()
-    return make_mesh_compat((n_hosts,), (axis,))
+    return make_auto_mesh((n_hosts,), (axis,))
 
 
 def dp_axes_for(mesh) -> tuple[str, ...]:

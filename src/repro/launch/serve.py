@@ -11,6 +11,7 @@ import numpy as np
 
 from repro.configs.registry import ARCH_IDS, get_config
 from repro.core import AttributionReport, EnergyProfiler
+from repro.launch.cache import enable_compilation_cache
 from repro.models import model as M
 from repro.serve.engine import Engine, Request, ServeConfig
 
@@ -24,6 +25,7 @@ def main(argv=None):
     ap.add_argument("--max-len", type=int, default=256)
     ap.add_argument("--smoke", action="store_true")
     args = ap.parse_args(argv)
+    enable_compilation_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

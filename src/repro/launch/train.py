@@ -18,8 +18,9 @@ import jax.numpy as jnp
 from repro.configs.base import ShapeConfig
 from repro.configs.registry import ARCH_IDS, get_config
 from repro.core import AttributionReport, EnergyProfiler
+from repro.launch.cache import enable_compilation_cache
 from repro.data.pipeline import SyntheticTokens
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_auto_mesh
 from repro.optim.adamw import AdamWConfig
 from repro.sharding import params as sp
 from repro.sharding.rules import axis_rules, make_rules
@@ -32,7 +33,7 @@ def parse_mesh(spec: str | None):
         return None
     dims = tuple(int(x) for x in spec.split("x"))
     axes = ("data", "model") if len(dims) == 2 else ("pod", "data", "model")
-    return make_mesh_compat(dims, axes)
+    return make_auto_mesh(dims, axes)
 
 
 def main(argv=None):
@@ -49,6 +50,7 @@ def main(argv=None):
     ap.add_argument("--compression", action="store_true",
                     help="int8 gradient compression with error feedback")
     args = ap.parse_args(argv)
+    enable_compilation_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

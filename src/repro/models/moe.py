@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig
 from repro.core.regions import region
 from repro.models.layers import Params, dense_init
@@ -171,7 +170,7 @@ def moe_ffn(p: Params, cfg: ModelConfig, x: jnp.ndarray, *,
         return jax.lax.psum(y, expert_axis)
 
     with region("moe_ffn"):
-        y2 = shard_map(
+        y2 = jax.shard_map(
             wrapped, mesh=mesh,
             in_specs=(tok_spec, rt_spec, rt_spec,
                       P(expert_axis, None, None), P(expert_axis, None, None),

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 
 __all__ = ["pipeline_forward", "bubble_fraction"]
 
@@ -87,7 +86,7 @@ def pipeline_forward(block_fn: Callable, mesh: Mesh, *, axis: str = "pipe",
         B = x.shape[0]
         assert B % n_micro == 0, (B, n_micro)
         xm = x.reshape(n_micro, B // n_micro, *x.shape[1:])
-        f = shard_map(
+        f = jax.shard_map(
             staged, mesh=mesh,
             in_specs=(P(axis), P()),      # layers split over stages
             out_specs=P(),

@@ -235,6 +235,9 @@ def test_runtime_registry_matches_static_declarations():
     import repro.core.exchange           # noqa: F401
     import repro.core.sampler            # noqa: F401
     import repro.core.sensors            # noqa: F401
+    import repro.serve.engine            # noqa: F401  (declares serve.*)
+    import repro.serve.recovery          # noqa: F401
+    import repro.serve.scheduler         # noqa: F401
     from repro.core.faults import FAULT_SITES, declared_sites
     assert set(declared_sites()) == set(FAULT_SITES)
 
@@ -256,7 +259,7 @@ def test_runtime_declare_rejects_unknown_and_cross_module_dup():
 def test_unscoped_x64_caught():
     bad = """\
         import jax
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         enable_x64()                                  # never entered
         jax.config.update("jax_enable_x64", True)     # global flip
@@ -268,7 +271,7 @@ def test_unscoped_x64_caught():
 
 def test_scoped_x64_passes():
     clean = """\
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         def f():
             with enable_x64():
@@ -276,6 +279,21 @@ def test_scoped_x64_passes():
     """
     assert _scan(clean, modpath="core/anything.py",
                  passes=[X64ScopingPass()]) == []
+
+
+def test_x64_scoping_reads_jax_namespace_spelling():
+    src = """\
+        import jax
+
+        def f():
+            with jax.enable_x64():
+                return 1
+
+        jax.enable_x64()                              # never entered
+    """
+    idents = [f.ident for f in _scan(src, modpath="core/anything.py",
+                                     passes=[X64ScopingPass()])]
+    assert idents == ["enable_x64-unscoped"]
 
 
 # ---------------------------------------------------------------------------
@@ -324,7 +342,7 @@ def test_baseline_reports_stale_keys(tmp_path):
 # ---------------------------------------------------------------------------
 
 def test_f64_leak_flagged():
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     with enable_x64():
         def leaky(x):
             return jnp.asarray(x, jnp.float64) * 2.0 + 1.0
@@ -342,7 +360,7 @@ def test_f32_code_not_flagged():
 
 
 def test_audit_recurses_into_control_flow():
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     with enable_x64():
         def looped(x):
             return jax.lax.fori_loop(

@@ -183,7 +183,7 @@ def test_chunk_step_carry_is_donated():
     buffers are consumed (no second live copy of the accumulators)."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     tls = _timelines(2, steps=20)
     dtl = dp.DeviceTimeline.from_timelines(tls)
     spec = InstantTraceSensor.make_spec()

@@ -35,7 +35,7 @@ _SUBPROCESS_SCRIPT = textwrap.dedent("""
     from repro.configs.registry import get_config
     from repro.configs.base import ShapeConfig
     from repro.launch.dryrun import build_rules
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_auto_mesh
     from repro.optim.adamw import AdamWConfig
     from repro.sharding import params as sp
     from repro.sharding.rules import axis_rules
@@ -62,7 +62,7 @@ _SUBPROCESS_SCRIPT = textwrap.dedent("""
     out["loss_single"] = float(m_ref["loss"])
 
     # (2, 2) mesh: DP x TP(+EP via shard_map) + FSDP state sharding
-    mesh = make_mesh_compat((2, 2), ("data", "model"))
+    mesh = make_auto_mesh((2, 2), ("data", "model"))
     rules = build_rules(cfg, shape, mesh)
     with axis_rules(rules):
         state2 = init_state(jax.random.PRNGKey(0), cfg, opt_cfg)

@@ -16,10 +16,10 @@ _SCRIPT = textwrap.dedent("""
     import warnings; warnings.filterwarnings("ignore")
     import jax
     from repro.launch.dryrun import lower_cell
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_auto_mesh
     from repro.configs.registry import get_config
 
-    mesh = make_mesh_compat((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_auto_mesh((2, 2, 2), ("pod", "data", "model"))
     cells = [("yi-6b", "train_4k"), ("qwen3-moe-30b-a3b", "decode_32k"),
              ("zamba2-1.2b", "long_500k"), ("hubert-xlarge", "prefill_32k"),
              ("xlstm-125m", "decode_32k"), ("hubert-xlarge", "decode_32k")]

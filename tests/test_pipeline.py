@@ -24,7 +24,7 @@ _SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp
     import numpy as np
     from repro.sharding.pipeline import pipeline_forward
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_auto_mesh
 
     L, D, B, M = 8, 16, 12, 6
     key = jax.random.PRNGKey(0)
@@ -45,7 +45,7 @@ _SCRIPT = textwrap.dedent("""
     for i in range(L):
         ref = layer(w[i], ref)
 
-    mesh = make_mesh_compat((4,), ("pipe",))
+    mesh = make_auto_mesh((4,), ("pipe",))
     run = pipeline_forward(stage_fn, mesh, axis="pipe", n_micro=M)
     out = jax.jit(run)(w, x)
     err = float(jnp.max(jnp.abs(out - ref)))
