@@ -32,6 +32,14 @@ onto the device:
   matmuls on TPU, XLA scatter-add elsewhere). Chunk padding/masking
   happens *inside* the step (lanes past the profiled horizon scatter out
   of bounds and drop) — no host-side ``np.concatenate`` padding.
+  Each stage of the step runs under a ``jax.named_scope``, so the
+  compiled program's ``op_name`` metadata, and hence a device trace,
+  names the stage of every operation: ``alea/clock`` (sample times, their
+  mask and clip), ``alea/lookup`` (every ``#(ends ≤ t)`` count),
+  ``alea/sensor`` (region-id gather, sensor emulation, channel sums,
+  idle blend) and ``alea/reduce`` (the carry update). Scopes nest; the
+  innermost ``alea/`` one names the operation. They are metadata only:
+  the computation graph is the same with or without them.
 
 * :func:`run_region_pipeline` — single-worker runs execute the whole scan
   in ONE jitted ``fori_loop``: no per-chunk dispatch, no per-chunk host
@@ -294,6 +302,7 @@ def _result_from_channels(counts, chan_psum, chan_psumsq, n, t_exec,
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("alea/clock")
 def _raw_chunk_times(root, k, c: int, period, jitter):
     """Chunk ``k``'s sample times: pure function of (key, k).
 
@@ -332,6 +341,7 @@ def num_chunks(t_end: float, period: float, chunk_size: int) -> int:
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("alea/lookup")
 def _count_le(ends_w, grid_w, cell_w, t, k_max: int):
     """``#(ends ≤ t)`` per sample — ``searchsorted(side="right")``, but
     through the precomputed grid: locate the cell (with exact-comparison
@@ -366,6 +376,7 @@ def _energy_at_cnt(bounds_w, eint_w, powers_w, m_w, x, cnt):
     return eint_w[idx] + (x - bounds_w[idx]) * powers_w[idx]
 
 
+@jax.named_scope("alea/sensor")
 def _sensor_powers(spec: SensorSpec, arrs, t, cnt_t, valid, prev,
                    k_max: int):
     """Per-worker sensor readings + updated RAPL prev-sample carry.
@@ -442,18 +453,21 @@ def _chunk_samples(arrs, spec: SensorSpec, root, k, c: int, period, jitter,
     """
     ends, bounds, eint, powers, rids, m_true, grid, cell = arrs
     t_raw = _raw_chunk_times(root, k, c, period, jitter)
-    valid = t_raw < t_end
-    t = jnp.minimum(t_raw, t_end)
+    with jax.named_scope("alea/clock"):
+        valid = t_raw < t_end
+        t = jnp.minimum(t_raw, t_end)
     cnt_t = jax.vmap(_count_le, in_axes=(0, 0, 0, None, None))(
         ends, grid, cell, t, k_max)
 
     def lookup(r_w, m_w, cnt_w):
         return r_w[jnp.clip(cnt_w, 0, m_w - 1)]
-    rid_mat = jax.vmap(lookup)(rids, m_true, cnt_t)
-    pows, prev = _sensor_powers(spec, arrs, t, cnt_t, valid, prev, k_max)
-    chan = pows.sum(axis=0)                  # [c] scalar | [D, c] rails
-    if chan.ndim == 2:
-        chan = jnp.concatenate([chan, chan.sum(axis=0, keepdims=True)])
+    with jax.named_scope("alea/sensor"):
+        rid_mat = jax.vmap(lookup)(rids, m_true, cnt_t)
+        pows, prev = _sensor_powers(spec, arrs, t, cnt_t, valid, prev,
+                                    k_max)
+        chan = pows.sum(axis=0)              # [c] scalar | [D, c] rails
+        if chan.ndim == 2:
+            chan = jnp.concatenate([chan, chan.sum(axis=0, keepdims=True)])
     return rid_mat, chan, valid, prev
 
 
@@ -482,6 +496,7 @@ def _check_spec_domains(spec: SensorSpec, dtl: "DeviceTimeline"):
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("alea/sensor")
 def _blend_idle(chan, frac, idle_power, idle_ch: int):
     """§4.7 suspension overhead: blend toward idle proportionally to the
     per-period suspension fraction (frac = 0 → identity). On the scalar

@@ -147,11 +147,16 @@ def make_carry_update(num_regions: int, *, use_pallas: bool | None = None,
     ``use_pallas`` defaults to backend dispatch: the Pallas one-hot matmul
     on TPU, an XLA scatter-add elsewhere (compiled, not interpret mode —
     interpret would put a Python loop back on the per-chunk path).
+
+    Every variant runs under ``jax.named_scope("alea/reduce")``: the
+    pipeline's stage name for the reduction in the compiled program's
+    ``op_name`` metadata and the device trace.
     """
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
 
     if use_pallas:
+        @jax.named_scope("alea/reduce")
         def update(counts, psum, psumsq, ids, pows, valid):
             ids_m = jnp.where(valid, ids, -1).astype(jnp.int32)
             if psum.ndim == 1:
@@ -187,6 +192,7 @@ def make_carry_update(num_regions: int, *, use_pallas: bool | None = None,
         # runs on the MXU, as one stacked [1 + 2C, c] @ [c, R] GEMM —
         # counts stay exact (integer-valued f64 sums), and XLA CPU
         # parallelizes dots where scatter is a serial loop.
+        @jax.named_scope("alea/reduce")
         def update(counts, psum, psumsq, ids, pows, valid):
             ids_m = jnp.where(valid, ids, -1)
             onehot = (ids_m[:, None]
@@ -209,6 +215,7 @@ def make_carry_update(num_regions: int, *, use_pallas: bool | None = None,
                     psum + stats[1:1 + d].T, psumsq + stats[1 + d:].T)
         return update
 
+    @jax.named_scope("alea/reduce")
     def update(counts, psum, psumsq, ids, pows, valid):
         # Invalid lanes scatter to index R, which is out of bounds for the
         # [R] carry and dropped — no branch, no extra dump slot to slice.

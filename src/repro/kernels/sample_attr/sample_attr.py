@@ -102,6 +102,7 @@ def sample_attr_pallas(region_ids: jnp.ndarray, powers: jnp.ndarray,
         out_specs=pl.BlockSpec((_ROWS, block_r), lambda j, i: (zero, j)),
         out_shape=jax.ShapeDtypeStruct((_ROWS, num_r_padded), jnp.float32),
         interpret=interpret,
+        name="sample_attr",
     )(region_ids.reshape(1, -1), powers.astype(jnp.float32).reshape(1, -1))
     return (out[0, :num_regions], out[1, :num_regions],
             out[2, :num_regions])

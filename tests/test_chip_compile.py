@@ -16,6 +16,9 @@ written for a described chip cannot be read back without one.
 
 from __future__ import annotations
 
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -83,8 +86,28 @@ def test_sample_attr_kernel_compiles(one_chip, num_regions):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+_INSTR = re.compile(r"^\s*(?:ROOT )?%(\S+) = (\w+)\[([\d,]*)\]\S* "
+                    r"(fusion|custom-call)\(")
+_STAGE = re.compile(r'op_name="[^"]*alea/(clock|lookup|sensor|reduce)\b')
+
+
+def _stage_by_large_op(hlo: str) -> dict[str, str | None]:
+    """Innermost ``alea/<stage>`` of each fusion or custom call of the
+    compiled program whose result holds at least a chunk of elements."""
+    out = {}
+    for line in hlo.splitlines():
+        m = _INSTR.match(line)
+        if m and math.prod(int(d) for d in m[3].split(",") if d) >= CHUNK:
+            stages = _STAGE.findall(line)
+            out[m[1]] = stages[-1] if stages else None
+    return out
+
+
 @pytest.mark.parametrize("domains", [False, True], ids=["D1", "D3"])
 def test_fused_region_step_compiles_with_pallas(one_chip, domains):
+    """The compiled step runs the Pallas reduction under its name, and
+    every large operation carries the stage that it belongs to in its
+    ``op_name``, as the device trace reports it."""
     (tl,) = _timelines(1, domains=domains)
     dtl = tl.to_device()
     spec = RaplTraceSensor.make_spec(domains=dtl.domains)
@@ -92,7 +115,12 @@ def test_fused_region_step_compiles_with_pallas(one_chip, domains):
         fn, args = dp.region_pipeline_call(dtl, spec, period=1e-3,
                                            use_pallas=True)
         compiled = fn.lower(*_on(one_chip, args)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    assert re.search(r"%sample_attr\.\d+ = f32\[8,", hlo)
+    stages = _stage_by_large_op(hlo)
+    assert stages and all(stages.values()), stages
+    assert {"lookup", "sensor", "reduce"} <= set(stages.values())
 
 
 def test_combo_chunk_step_compiles(one_chip):

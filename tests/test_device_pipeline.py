@@ -206,6 +206,35 @@ def test_chunk_step_carry_is_donated():
 
 
 # ---------------------------------------------------------------------------
+# Stage names: each stage of the chunk step is a named scope, so the
+# compiled program's op_name metadata (and a device trace) names it.
+# ---------------------------------------------------------------------------
+
+STAGES = ("clock", "lookup", "sensor", "reduce")
+
+
+@pytest.mark.parametrize("domains", [False, True], ids=["D1", "D3"])
+def test_region_pipeline_hlo_names_every_stage(domains):
+    import re
+
+    from jax import enable_x64
+    costs = [RegionCost("mem", flops=1e10, hbm_bytes=5e10, invocations=4),
+             RegionCost("alu", flops=6e11, hbm_bytes=2e9, invocations=4)]
+    dtl = synthesize(costs, steps=8, seed=0, domains=domains).to_device()
+    spec = RaplTraceSensor.make_spec(domains=dtl.domains)
+    with enable_x64():
+        fn, args = dp.region_pipeline_call(dtl, spec, period=10e-3,
+                                           chunk_size=1024)
+        hlo = fn.lower(*args).as_text(dialect="hlo", debug_info=True)
+    op_names = re.findall(r'op_name="([^"]*)"', hlo)
+    assert {m for n in op_names
+            for m in re.findall(r"alea/(\w+)", n)} == set(STAGES)
+    # Scopes nest, and the innermost names the operation: the lookups of
+    # the RAPL refresh times sit inside the sensor stage.
+    assert any(re.search(r"alea/sensor/.*alea/lookup", n) for n in op_names)
+
+
+# ---------------------------------------------------------------------------
 # DeviceTimeline substrate.
 # ---------------------------------------------------------------------------
 
