@@ -23,11 +23,13 @@ onto the device:
 
 * **Fused chunk step** — one jitted fixed-shape step per chunk: time
   generation, vectorized region lookup (``searchsorted(side="right")``
-  semantics through a precomputed per-worker grid accelerator, ``vmap``
-  over the worker axis), trace-sensor emulation as pure functions of the
-  energy integral (RAPL differencing with a one-scalar prev-sample carry,
-  INA231 window semantics), and the ``sample_attr`` reduction folding
-  into a donated ``(counts, Σpow, Σpow²)`` carry
+  semantics: a per-worker time grid bounds each count to a window of
+  ``grid_k + 1`` candidates, which a ``ceil(log2(grid_k + 1))``-step
+  binary search resolves; ``vmap`` over the worker axis), trace-sensor
+  emulation as pure functions of the energy integral (RAPL differencing
+  with a one-scalar prev-sample carry, INA231 window semantics), and the
+  ``sample_attr`` reduction folding into a donated ``(counts, Σpow,
+  Σpow²)`` carry
   (:func:`repro.kernels.sample_attr.ops.make_carry_update`: Pallas one-hot
   matmuls on TPU, XLA scatter-add elsewhere). Chunk padding/masking
   happens *inside* the step (lanes past the profiled horizon scatter out
@@ -117,11 +119,6 @@ _TABLE_MIN = 64
 
 _GRID_OVERSAMPLE = 4        # grid cells per interval (amortizes window K)
 _GRID_MAX = 1 << 20
-# Heavy-tailed durations (one long interval + many micro-intervals) can
-# concentrate intervals in one grid cell; past this window the unrolled
-# compare loop loses to a plain O(log m) binary search, so grid_k = 0
-# (sentinel) routes lookups to jnp.searchsorted instead.
-_GRID_K_MAX = 32
 
 
 @dataclasses.dataclass(frozen=True)
@@ -148,10 +145,14 @@ class DeviceTimeline:
 
     ``grid``/``cell``/``grid_k`` form the lookup accelerator: per worker,
     ``grid[g] = #(ends ≤ g·cell)`` on a uniform time grid, with ``grid_k``
-    the maximum interval count of any cell. An interval lookup is then one
-    grid gather plus ``grid_k`` *consecutive* compares — exactly
-    ``searchsorted(side="right")``, at O(1) instead of O(log m) random
-    accesses (the device hot path's dominant cost). Because
+    the maximum interval count of any cell. A time in cell ``g`` counts
+    between ``grid[g]`` and ``grid[g] + grid_k`` ends, so an interval
+    lookup is one grid gather plus a binary search of that window in
+    :attr:`lookup_steps` ``= ceil(log2(grid_k + 1))`` gathers — exactly
+    ``searchsorted(side="right")``, at a cost set by the densest cell
+    instead of by ``log2(m)`` (the device hot path's dominant cost).
+    Bursty timelines only widen the window; since ``grid_k ≤ m`` it never
+    takes more steps than a search of every end. Because
     ``bounds = [0, ends...]``, the energy-interpolation index derives from
     the same count: ``#(bounds ≤ t) = 1 + #(ends ≤ t)`` — one structure
     accelerates both lookups.
@@ -178,6 +179,13 @@ class DeviceTimeline:
     @property
     def num_domains(self) -> int:
         return len(self.domains)
+
+    @property
+    def lookup_steps(self) -> int:
+        """Binary-search steps of one interval lookup: ``ceil(log2(grid_k
+        + 1))``, enough to resolve a cell's window of ``grid_k + 1``
+        possible counts."""
+        return _search_steps(self.grid_k)
 
     @classmethod
     def from_timelines(cls, timelines: list[Timeline]) -> "DeviceTimeline":
@@ -229,8 +237,6 @@ class DeviceTimeline:
             pts = np.arange(G + 2, dtype=np.float64) * cell[w]
             grid[w] = np.searchsorted(tl.ends, pts, side="right")
             grid_k = max(grid_k, int(np.diff(grid[w]).max()))
-        if grid_k > _GRID_K_MAX:
-            grid_k = 0      # searchsorted fallback (see _count_le)
         with enable_x64():
             return cls(ends=jnp.asarray(ends), bounds=jnp.asarray(bounds),
                        eint=jnp.asarray(eint), powers=jnp.asarray(powers),
@@ -341,30 +347,34 @@ def num_chunks(t_end: float, period: float, chunk_size: int) -> int:
 # ---------------------------------------------------------------------------
 
 
+def _search_steps(grid_k: int) -> int:
+    """``ceil(log2(grid_k + 1))``: the halvings that resolve a window of
+    ``grid_k + 1`` possible counts."""
+    return int(grid_k).bit_length()
+
+
 @jax.named_scope("alea/lookup")
-def _count_le(ends_w, grid_w, cell_w, t, k_max: int):
+def _count_le(ends_w, grid_w, cell_w, t, grid_k: int):
     """``#(ends ≤ t)`` per sample — ``searchsorted(side="right")``, but
-    through the precomputed grid: locate the cell (with exact-comparison
-    guards against division rounding), start from its prefix count, and
-    add at most ``k_max`` consecutive compares. All comparisons are exact,
-    so this is bit-equal to the numpy reference's searchsorted.
-    ``k_max = 0`` means the timeline's durations were too heavy-tailed
-    for a bounded window (see ``_GRID_K_MAX``) — use the real binary
-    search (same result, O(log m))."""
-    if k_max == 0:
-        return jnp.searchsorted(ends_w, t, side="right").astype(jnp.int32)
+    bounded by the precomputed grid: locate the cell (with exact-comparison
+    guards against division rounding); its count lies in ``[grid[g],
+    grid[g] + grid_k]``, so a branchless binary search from ``grid[g]``
+    with steps ``2^(S-1), …, 2, 1`` (``S = ceil(log2(grid_k + 1))``, their
+    sum ``2^S - 1 ≥ grid_k``) resolves it. ``ends`` are sorted and padded
+    with ``+inf``; only integer arithmetic and exact compares are
+    involved, so this is bit-equal to the numpy reference's
+    searchsorted."""
     G = grid_w.shape[0] - 2
     g = jnp.floor(t / cell_w).astype(jnp.int32)
     g = g - (g * cell_w > t)
     g = g + ((g + 1) * cell_w <= t)
     g = jnp.clip(g, 0, G)
-    lo = grid_w[g]
     M = ends_w.shape[0]
-    cnt = lo
-    for j in range(k_max):
-        pos = lo + j
-        cnt = cnt + ((pos < M)
-                     & (ends_w[jnp.minimum(pos, M - 1)] <= t))
+    cnt = grid_w[g]
+    for j in reversed(range(_search_steps(grid_k))):
+        pos = cnt + ((1 << j) - 1)
+        take = (pos < M) & (ends_w[jnp.minimum(pos, M - 1)] <= t)
+        cnt = cnt + jnp.where(take, 1 << j, 0)
     return cnt
 
 
@@ -378,7 +388,7 @@ def _energy_at_cnt(bounds_w, eint_w, powers_w, m_w, x, cnt):
 
 @jax.named_scope("alea/sensor")
 def _sensor_powers(spec: SensorSpec, arrs, t, cnt_t, valid, prev,
-                   k_max: int):
+                   grid_k: int):
     """Per-worker sensor readings + updated RAPL prev-sample carry.
 
     Scalar substrates (``powers`` [W, M]) return [W, c] — the verbatim
@@ -422,9 +432,9 @@ def _sensor_powers(spec: SensorSpec, arrs, t, cnt_t, valid, prev,
         # needs its own tiny lookup.
         prev0 = jnp.where(prev < 0.0, jnp.maximum(tq[0] - up, 0.0), prev)
         e_q = e_at(bounds, eint, powers, m_true, tq,
-                   count(ends, grid, cell, tq, k_max))
+                   count(ends, grid, cell, tq, grid_k))
         e_p0 = e_at(bounds, eint, powers, m_true, prev0[None],
-                    count(ends, grid, cell, prev0[None], k_max))
+                    count(ends, grid, cell, prev0[None], grid_k))
         e_prev = jnp.concatenate([e_p0, e_q[..., :-1]], axis=-1)
         prev_vec = jnp.concatenate([prev0[None], tq[:-1]])
         dt = jnp.maximum(tq - prev_vec, up)
@@ -435,13 +445,13 @@ def _sensor_powers(spec: SensorSpec, arrs, t, cnt_t, valid, prev,
         lo = jnp.maximum(t - spec.window, 0.0)
         e_t = e_at(bounds, eint, powers, m_true, t, cnt_t)
         e_lo = e_at(bounds, eint, powers, m_true, lo,
-                    count(ends, grid, cell, lo, k_max))
+                    count(ends, grid, cell, lo, grid_k))
         return (e_t - e_lo) / jnp.maximum(t - lo, 1e-12), prev
     raise ValueError(f"unknown trace sensor kind: {spec.kind!r}")
 
 
 def _chunk_samples(arrs, spec: SensorSpec, root, k, c: int, period, jitter,
-                   t_end, prev, k_max: int):
+                   t_end, prev, grid_k: int):
     """One fused chunk: times → region ids [W, c] → channel powers.
 
     Scalar substrates produce the summed power [c] (the pre-rail graph);
@@ -457,14 +467,14 @@ def _chunk_samples(arrs, spec: SensorSpec, root, k, c: int, period, jitter,
         valid = t_raw < t_end
         t = jnp.minimum(t_raw, t_end)
     cnt_t = jax.vmap(_count_le, in_axes=(0, 0, 0, None, None))(
-        ends, grid, cell, t, k_max)
+        ends, grid, cell, t, grid_k)
 
     def lookup(r_w, m_w, cnt_w):
         return r_w[jnp.clip(cnt_w, 0, m_w - 1)]
     with jax.named_scope("alea/sensor"):
         rid_mat = jax.vmap(lookup)(rids, m_true, cnt_t)
         pows, prev = _sensor_powers(spec, arrs, t, cnt_t, valid, prev,
-                                    k_max)
+                                    grid_k)
         chan = pows.sum(axis=0)              # [c] scalar | [D, c] rails
         if chan.ndim == 2:
             chan = jnp.concatenate([chan, chan.sum(axis=0, keepdims=True)])

@@ -2,6 +2,8 @@
 clock): bit-exact counts, float64-tolerance sums, donated carries, and the
 profiler/benchmark wiring."""
 
+import math
+
 import numpy as np
 import pytest
 
@@ -238,17 +240,25 @@ def test_region_pipeline_hlo_names_every_stage(domains):
 # DeviceTimeline substrate.
 # ---------------------------------------------------------------------------
 
-def test_heavy_tailed_durations_fall_back_to_searchsorted():
-    """One long interval + many micro-intervals concentrates intervals in
-    a single grid cell; past _GRID_K_MAX the accelerator must hand the
-    lookup to a real binary search (same results, bounded compile)."""
+def _heavy_tailed_timeline():
+    """One long interval + 4,000 micro-intervals: the micro-intervals
+    crowd ~160 to a grid cell."""
     rng = np.random.default_rng(31)
     m = 4000
-    tl = Timeline(rng.integers(0, 4, m + 1).astype(np.int32),
-                  np.concatenate([[5.0], rng.uniform(1e-6, 3e-6, m)]),
-                  50.0 + 100.0 * rng.random(m + 1), ("a", "b", "c", "d"))
+    return Timeline(rng.integers(0, 4, m + 1).astype(np.int32),
+                    np.concatenate([[5.0], rng.uniform(1e-6, 3e-6, m)]),
+                    50.0 + 100.0 * rng.random(m + 1), ("a", "b", "c", "d"))
+
+
+def test_heavy_tailed_durations_take_the_bounded_search():
+    """Many intervals in one grid cell only widen the cell's window: the
+    lookup binary-searches it in ceil(log2(grid_k + 1)) steps, fewer than
+    a search of every end, with the reference's results."""
+    tl = _heavy_tailed_timeline()
     dtl = tl.to_device()
-    assert dtl.grid_k == 0          # fallback engaged
+    assert dtl.grid_k > 32
+    assert dtl.lookup_steps == math.ceil(math.log2(dtl.grid_k + 1))
+    assert dtl.lookup_steps < math.ceil(math.log2(len(tl.ends) + 1))
     spec = InstantTraceSensor.make_spec()
     res = dp.run_region_pipeline(dtl, spec, period=5e-3, jitter=100e-6,
                                  seed=2, chunk_size=512)
@@ -258,6 +268,101 @@ def test_heavy_tailed_durations_fall_back_to_searchsorted():
     assert res.n == ref.n
     _assert_stats_close((res.counts, res.psum, res.psumsq),
                         (ref.counts, ref.psum, ref.psumsq))
+
+
+def _count_le_all(dtl, t):
+    """``_count_le`` as the pipeline calls it: vmapped over workers, the
+    sample times shared, jitted under x64. Returns [W, len(t)]."""
+    import jax
+    import jax.numpy as jnp
+    from jax import enable_x64
+    count = jax.jit(jax.vmap(dp._count_le, in_axes=(0, 0, 0, None, None)),
+                    static_argnums=4)
+    with enable_x64():
+        return np.asarray(count(dtl.ends, dtl.grid, dtl.cell,
+                                jnp.asarray(t, jnp.float64), dtl.grid_k))
+
+
+def _burst_timeline(b, seed=0, n_long=40, tiny=1e-7):
+    """Long intervals with a burst of ``b`` tiny ones in their middle:
+    the burst and the long interval before it share a cell."""
+    rng = np.random.default_rng(seed)
+    longs = rng.uniform(0.5, 1.5, n_long)
+    d = np.concatenate([longs[:n_long // 2], np.full(b, tiny),
+                        longs[n_long // 2:]])
+    m = len(d)
+    return Timeline(np.arange(m) % 3, d, 50.0 + 10.0 * rng.random(m),
+                    ("a", "b", "c"))
+
+
+def _one_cell_timeline(m=200):
+    """A long interval, then m - 1 of a nanosecond: every end falls in
+    the last grid cell."""
+    return Timeline(np.arange(m) % 3,
+                    np.concatenate([[1.0], np.full(m - 1, 1e-9)]),
+                    np.full(m, 60.0), ("a", "b", "c"))
+
+
+_LOOKUP_CASES = {
+    # name: (timelines, grid_k the case must reach)
+    "k1": (lambda: [_burst_timeline(0)], 1),
+    "k2": (lambda: [_burst_timeline(1)], 2),
+    "k3": (lambda: [_burst_timeline(2)], 3),
+    "k57_burst": (lambda: [_burst_timeline(56)], 57),
+    "one_cell": (lambda: [_one_cell_timeline()], 200),
+    "ragged_pair": (lambda: [_burst_timeline(56, seed=1),
+                             _burst_timeline(3, seed=2, n_long=12)], 57),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_LOOKUP_CASES))
+def test_count_le_equals_searchsorted_exactly(case):
+    """The grid-bounded search is ``searchsorted(side="right")`` bit for
+    bit: at every interval end and one ulp either side, at every grid
+    point, at 0 and the horizon, past the last interval, and at random
+    times, for every worker of a ragged (``+inf``-padded) batch."""
+    build, want_k = _LOOKUP_CASES[case]
+    tls = build()
+    dtl = dp.DeviceTimeline.from_timelines(tls)
+    assert dtl.grid_k == want_k
+    G = dtl.grid.shape[1] - 2
+    t_max = max(tl.t_exec for tl in tls)
+    q = [np.array([0.0, dtl.t_end, t_max, np.nextafter(t_max, np.inf)])]
+    for tl in tls:
+        q += [tl.ends, np.nextafter(tl.ends, -np.inf),
+              np.nextafter(tl.ends, np.inf)]
+    # Grid points exactly as from_timelines and the lookup form them.
+    q += [np.arange(G + 2, dtype=np.float64) * c
+          for c in np.asarray(dtl.cell)]
+    q.append(np.random.default_rng(7).uniform(0.0, t_max, 2000))
+    t = np.concatenate(q)
+    got = _count_le_all(dtl, t)
+    for w, tl in enumerate(tls):
+        np.testing.assert_array_equal(
+            got[w], np.searchsorted(tl.ends, t, side="right"))
+
+
+def test_lookup_is_a_bounded_unrolled_search():
+    """The traced lookup has no loop (a full ``searchsorted`` would bring
+    a ``while``) and gathers ``ends`` once per search step."""
+    import jax
+    import jax.numpy as jnp
+    from jax import enable_x64
+
+    from repro.analysis.jaxpr_audit import iter_eqns
+    dtl = _heavy_tailed_timeline().to_device()
+    with enable_x64():
+        jaxpr = jax.make_jaxpr(
+            jax.vmap(dp._count_le, in_axes=(0, 0, 0, None, None)),
+            static_argnums=4)(dtl.ends, dtl.grid, dtl.cell,
+                              jnp.zeros(512, jnp.float64), dtl.grid_k)
+    eqns = list(iter_eqns(jaxpr))
+    assert not [e for e in eqns if e.primitive.name == "while"]
+    ends_gathers = [
+        e for e in eqns if e.primitive.name == "gather"
+        and e.invars[0].aval.shape == dtl.ends.shape
+        and e.invars[0].aval.dtype == np.float64]
+    assert len(ends_gathers) == dtl.lookup_steps
 
 
 def test_device_timeline_ragged_workers_pad():
