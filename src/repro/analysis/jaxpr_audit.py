@@ -312,8 +312,7 @@ def _combo_audit(domains: bool) -> tuple:
                  jnp.zeros((), jnp.int64),
                  -jnp.ones((), jnp.float64))
         args = (carry, table, table_ids, n_rows, *dtl.arrays(),
-                jax.random.PRNGKey(0), jnp.int32(0),
-                jnp.float64(10e-3), jnp.float64(200e-6),
+                jax.ShapeDtypeStruct((_CHUNK,), jnp.float64),
                 jnp.float64(dtl.t_end))
         jaxpr = jax.make_jaxpr(step)(*args)
         donated = donation_of_jitted(step, *args,

@@ -56,7 +56,15 @@ onto the device:
   (:class:`~repro.core.streaming.CombinationInterner` — the id space stays
   host-authoritative because it is dynamic and ordered), rebuild the
   sorted table, and fold the chunk through a fixed-shape device update.
-  Steady state (stable combination set) transfers no sample arrays at all.
+  Each chunk's sample times are drawn on the host's CPU
+  (:func:`_ref_times`, IEEE float64) and uploaded, so every worker's
+  lookup sees the contract's exact nanoseconds on a device that emulates
+  float64. Once the combination set is stable no other sample array is
+  transferred; on SPMD traffic, where skew and jitter keep making new
+  combinations, every chunk misses (see :func:`run_combo_pipeline`). The
+  key packing and table search run under ``alea/combo_key``, the scatters
+  under ``alea/combo_fold``, and the host's miss path under the host trace
+  spans ``alea/miss`` (``/recompute``, ``/intern``, ``/table``).
 
 Everything runs under ``enable_x64`` (cf. :mod:`repro.core.exchange`):
 float64 times make device region lookups bit-identical to the numpy
@@ -315,7 +323,9 @@ def _raw_chunk_times(root, k, c: int, period, jitter):
     ``t_i = u0 + i·T + u_i`` on an integer-nanosecond clock. The ns
     quantization is part of the contract: it models a real timer's
     resolution and pins the float64 value exactly, so the numpy reference
-    recovers identical region lookups.
+    recovers identical region lookups. Exactly in IEEE float64: where the
+    device emulates float64 (the TPU), a time can round to the
+    neighbouring nanosecond.
     """
     dt = period.dtype
     u0 = jax.random.uniform(jax.random.fold_in(root, 0), (), dt, 0.0, period)
@@ -461,8 +471,14 @@ def _chunk_samples(arrs, spec: SensorSpec, root, k, c: int, period, jitter,
     horizon are flagged invalid and their times clipped to ``t_end`` so
     the sensor math stays finite (they contribute nothing downstream).
     """
+    return _samples_at(arrs, spec, _raw_chunk_times(root, k, c, period,
+                                                    jitter),
+                       t_end, prev, grid_k)
+
+
+def _samples_at(arrs, spec: SensorSpec, t_raw, t_end, prev, grid_k: int):
+    """:func:`_chunk_samples` at the sample times ``t_raw``."""
     ends, bounds, eint, powers, rids, m_true, grid, cell = arrs
-    t_raw = _raw_chunk_times(root, k, c, period, jitter)
     with jax.named_scope("alea/clock"):
         valid = t_raw < t_end
         t = jnp.minimum(t_raw, t_end)
@@ -691,36 +707,32 @@ def _pack_rows(rid_mat, pack: tuple[int, int, int]):
 def _combo_step_fn(chunk_size: int, spec: SensorSpec, grid_k: int,
                    pack: tuple[int, int, int]):
     def step(carry, table, table_ids, n_rows, ends, bounds, eint, powers,
-             rids, m_true, grid, cell, root, k, period, jitter, t_end):
+             rids, m_true, grid, cell, t_raw, t_end):
         counts, psum, psumsq, n, prev = carry
         prev_in = prev      # pre-chunk sensor state, for miss replay
         arrs = (ends, bounds, eint, powers, rids, m_true, grid, cell)
-        rid_mat, chan, valid, prev = _chunk_samples(
-            arrs, spec, root, k, chunk_size, period, jitter, t_end, prev,
-            grid_k)
+        rid_mat, chan, valid, prev = _samples_at(arrs, spec, t_raw, t_end,
+                                                 prev, grid_k)
         cap = counts.shape[0]
-        keys = _pack_rows(rid_mat, pack)
-        if pack[2] == 1:
-            # One int64 key per sample → scalar binary search.
-            flat = keys[:, 0]
-            pos = jnp.searchsorted(table[:, 0], flat, side="left")
-            pos = jnp.minimum(pos, table.shape[0] - 1).astype(jnp.int32)
-            found = (pos < n_rows) & (table[pos, 0] == flat)
-        else:
-            pos, found = _lex_search(table, n_rows, keys)
-        # Any in-horizon row missing from the table aborts the on-device
-        # fold for the WHOLE chunk — the host interns it and re-folds, so
-        # no sample is ever half-counted.
-        any_miss = jnp.any(valid & ~found)
-        fold = valid & found & ~any_miss
-        idx = jnp.where(fold, table_ids[pos], cap)
-        counts = counts.at[idx].add(jnp.ones((), counts.dtype), mode="drop")
-        if psum.ndim == 1:      # scalar substrate: the pre-rail graph
-            psum = psum.at[idx].add(chan, mode="drop")
-            psumsq = psumsq.at[idx].add(chan * chan, mode="drop")
-        else:
-            psum = psum.at[idx].add(chan.T, mode="drop")
-            psumsq = psumsq.at[idx].add((chan * chan).T, mode="drop")
+        with jax.named_scope("alea/combo_key"):
+            keys = _pack_rows(rid_mat, pack)
+            if pack[2] == 1:
+                # One int64 key per sample → scalar binary search.
+                flat = keys[:, 0]
+                pos = jnp.searchsorted(table[:, 0], flat, side="left")
+                pos = jnp.minimum(pos, table.shape[0] - 1).astype(jnp.int32)
+                found = (pos < n_rows) & (table[pos, 0] == flat)
+            else:
+                pos, found = _lex_search(table, n_rows, keys)
+            # Any in-horizon row missing from the table aborts the
+            # on-device fold for the WHOLE chunk — the host interns it and
+            # re-folds, so no sample is ever half-counted.
+            any_miss = jnp.any(valid & ~found)
+            fold = valid & found & ~any_miss
+            idx = jnp.where(fold, table_ids[pos], cap)
+        with jax.named_scope("alea/combo_fold"):
+            counts, psum, psumsq = _scatter_stats(counts, psum, psumsq,
+                                                  idx, chan)
         carry = (counts, psum, psumsq, n + jnp.sum(fold), prev)
         return carry, any_miss, prev_in
 
@@ -730,24 +742,21 @@ def _combo_step_fn(chunk_size: int, spec: SensorSpec, grid_k: int,
 @functools.lru_cache(maxsize=None)
 def _chunk_recompute_fn(chunk_size: int, spec: SensorSpec, grid_k: int):
     """Miss-path sample recomputation: identical to the step's internal
-    chunk (purely counter-based, so replaying chunk k is exact) — keeps
+    chunk at the same sample times, so replaying chunk k is exact — keeps
     sample arrays out of the steady-state step's outputs entirely."""
     def recompute(ends, bounds, eint, powers, rids, m_true, grid, cell,
-                  root, k, period, jitter, t_end, prev):
+                  t_raw, t_end, prev):
         arrs = (ends, bounds, eint, powers, rids, m_true, grid, cell)
-        rid_mat, chan, valid, _ = _chunk_samples(
-            arrs, spec, root, k, chunk_size, period, jitter, t_end, prev,
-            grid_k)
+        rid_mat, chan, valid, _ = _samples_at(arrs, spec, t_raw, t_end,
+                                              prev, grid_k)
         return rid_mat, chan, valid
     return jax.jit(recompute)
 
 
-def _combo_fold(carry, idx, pows, valid):
-    """Fixed-shape host-assisted fold for miss chunks: encoded combination
-    ids (padded with the out-of-bounds cap index) scatter into the donated
-    carry exactly like the on-device path would have. ``pows`` is [c]
-    (scalar substrate) or the [C, c] channel matrix."""
-    counts, psum, psumsq, n, prev = carry
+def _scatter_stats(counts, psum, psumsq, idx, pows):
+    """Scatter-add one chunk into the combination statistics; ``idx`` past
+    the end drops the sample. ``pows`` is [c] (scalar substrate: the
+    pre-rail graph) or the [C, c] channel matrix."""
     counts = counts.at[idx].add(jnp.ones((), counts.dtype), mode="drop")
     if psum.ndim == 1:
         psum = psum.at[idx].add(pows, mode="drop")
@@ -755,6 +764,16 @@ def _combo_fold(carry, idx, pows, valid):
     else:
         psum = psum.at[idx].add(pows.T, mode="drop")
         psumsq = psumsq.at[idx].add((pows * pows).T, mode="drop")
+    return counts, psum, psumsq
+
+
+@jax.named_scope("alea/combo_fold")
+def _combo_fold(carry, idx, pows, valid):
+    """Fixed-shape host-assisted fold for miss chunks: encoded combination
+    ids (padded with the out-of-bounds cap index) scatter into the donated
+    carry exactly like the on-device path would have."""
+    counts, psum, psumsq, n, prev = carry
+    counts, psum, psumsq = _scatter_stats(counts, psum, psumsq, idx, pows)
     return (counts, psum, psumsq, n + jnp.sum(valid), prev)
 
 
@@ -779,6 +798,47 @@ def _build_table(interner: CombinationInterner, cap: int, width: int,
         return jnp.asarray(table), jnp.asarray(ids), jnp.int32(k)
 
 
+def _grow_carry(carry, pad: int):
+    """The combination carry with ``pad`` more zero rows."""
+    counts, psum, psumsq, n, prev = carry
+    pad_stat = (pad, *psum.shape[1:])
+    return (jnp.concatenate([counts, jnp.zeros(pad, counts.dtype)]),
+            jnp.concatenate([psum, jnp.zeros(pad_stat, psum.dtype)]),
+            jnp.concatenate([psumsq, jnp.zeros(pad_stat, psumsq.dtype)]),
+            n, prev)
+
+
+def _admit_or_fold(interner: CombinationInterner, rows: np.ndarray,
+                   max_combinations: int, other_by_region: dict[int, int],
+                   width: int) -> tuple[np.ndarray, int]:
+    """Combination ids of a miss chunk's int64 ``rows`` in the bounded
+    tier, and the samples folded into a tail row: new rows are interned
+    while fewer than ``max_combinations`` identified rows exist; later
+    arrivals fold into their region's ``other`` sentinel row, so the
+    table/carry stop growing. Folded keys stay out of the device table —
+    their traffic keeps re-missing — but each miss lands here and folds
+    exactly once per sample, so nothing is lost."""
+    uniq, inverse = np.unique(rows, axis=0, return_inverse=True)
+    uids = np.empty(len(uniq), np.int64)
+    folds = 0
+    for i in range(len(uniq)):
+        key = tuple(int(v) for v in uniq[i])
+        cid = interner.find_row(uniq[i])
+        if cid is None:
+            resident = len(interner) - len(other_by_region)
+            if resident < max_combinations:
+                cid = interner.intern(key)
+            else:
+                region = key[0]
+                cid = other_by_region.get(region)
+                if cid is None:
+                    cid = interner.intern(other_row(region, width))
+                    other_by_region[region] = cid
+                folds += int(np.sum(inverse == i))
+        uids[i] = cid
+    return uids[inverse.reshape(-1)], folds
+
+
 def run_combo_pipeline(dtl: DeviceTimeline, spec: SensorSpec, *,
                        period: float, jitter: float = 200e-6, seed: int = 0,
                        chunk_size: int = DEFAULT_CHUNK,
@@ -787,14 +847,26 @@ def run_combo_pipeline(dtl: DeviceTimeline, spec: SensorSpec, *,
                        ) -> tuple[StreamingCombinationAggregator, int]:
     """Fused multi-worker (§4.4) combination attribution.
 
-    Steady state is fully device-resident: the jitted chunk step looks
-    every sample's worker-region row up in the device-side lex-sorted
-    combination table and scatters into the donated carry; only a scalar
-    miss flag is read back per chunk. Chunks that surface a new
-    combination fall back to the host interner (the combination id space
-    is dynamic and first-appearance-ordered — host-authoritative), after
-    which the rebuilt table is re-uploaded; with a stable combination set
-    that happens O(distinct combos / chunk) times total.
+    The chunk's sample times come from the host's CPU (:func:`_ref_times`:
+    8 bytes a sample uploaded, exact where the device emulates float64).
+    The jitted chunk step looks every sample's worker-region row up in
+    the device-side lex-sorted combination table and scatters into the
+    donated carry; only a scalar miss flag is read back per chunk. A chunk
+    that surfaces a new combination falls back to the host: the chunk is
+    computed again on the device and read back, its rows are interned
+    (the combination id space is dynamic and first-appearance-ordered —
+    host-authoritative), and the rebuilt table is re-uploaded.
+
+    Whether the device-resident steady state arrives depends on the
+    traffic. Where the workers' phases repeat (lockstep or fixed-offset
+    replicas), the combination set stops growing and misses stop after
+    O(distinct combos / chunk) chunks. On SPMD traffic — every worker
+    running the same steps with launch skew and op-time jitter, and a
+    barrier at every step — the skew keeps producing new combinations:
+    in the ``combo-call-rapl-w16.spmd-barrier`` benchmark cell (16
+    workers, 65,536-sample chunks) each chunk brings thousands of new
+    rows, ``miss_chunks == chunks``, and every chunk is computed twice
+    and interned on the host.
 
     ``max_combinations`` bounds the attribution state (heavy-hitters
     tier, see :mod:`repro.core.sketch`): the miss path *admits* new
@@ -812,9 +884,12 @@ def run_combo_pipeline(dtl: DeviceTimeline, spec: SensorSpec, *,
     Returns ``(aggregator, n_samples)`` — the aggregator is a regular
     :class:`StreamingCombinationAggregator`, so merge/exchange/estimates
     compose exactly as with the host path. ``stats``, if given, records
-    ``chunks`` and ``miss_chunks`` (host-fallback count — the
-    steady-state zero-transfer claim is ``miss_chunks ≪ chunks``) plus,
-    in bounded mode, ``tail_folds``.
+    ``chunks``, ``miss_chunks`` (host-fallback count — the steady state
+    holds where ``miss_chunks ≪ chunks``), ``distinct_combinations`` (the
+    interner's rows at the end), ``rows_interned`` (sample rows read back
+    and interned on the host) and ``table_rebuilds`` (device table
+    uploads after the first, one a miss chunk) plus, in bounded mode,
+    ``tail_folds``.
     """
     _check_sampling_args(spec, period, jitter)
     _check_spec_domains(spec, dtl)
@@ -830,6 +905,8 @@ def run_combo_pipeline(dtl: DeviceTimeline, spec: SensorSpec, *,
                 "the region pipeline")
     miss_chunks = 0
     tail_folds = 0
+    rows_interned = 0
+    table_rebuilds = 0
     other_by_region: dict[int, int] = {}
     n_chan = num_channels(dtl.num_domains)
     pack = _pack_spec(dtl.num_regions, W)
@@ -844,75 +921,47 @@ def run_combo_pipeline(dtl: DeviceTimeline, spec: SensorSpec, *,
                  jnp.zeros(stat_shape, jnp.float64),
                  jnp.zeros((), jnp.int64),
                  -jnp.ones((), jnp.float64))
-        root = jax.random.PRNGKey(seed)
-        period_j = jnp.float64(period)
-        jitter_j = jnp.float64(jitter)
         t_end_j = jnp.float64(dtl.t_end)
         k_chunks = num_chunks(dtl.t_end, period, chunk_size)
         for k in range(k_chunks):
+            t_raw = jnp.asarray(_ref_times(seed, k, period, jitter,
+                                           chunk_size))
             carry, miss, prev_in = step(
-                carry, table, table_ids, n_rows, *dtl.arrays(), root,
-                jnp.int32(k), period_j, jitter_j, t_end_j)
+                carry, table, table_ids, n_rows, *dtl.arrays(), t_raw,
+                t_end_j)
             if not bool(miss):
                 continue
             # Miss path: replay this one chunk (counter-based times make
             # the replay exact), intern the new rows, rebuild, re-fold.
             miss_chunks += 1
-            rid_dev, total_dev, valid_dev = _chunk_recompute_fn(
-                chunk_size, spec, dtl.grid_k)(
-                    *dtl.arrays(), root, jnp.int32(k), period_j, jitter_j,
-                    t_end_j, prev_in)
-            valid = np.asarray(valid_dev)
-            rows = np.asarray(rid_dev).T[valid]
-            if max_combinations is None:
-                cids = interner.encode(rows.astype(np.int64))
-            else:
-                # Admit-or-fold (bounded tier): intern new rows while
-                # fewer than max_combinations identified rows exist;
-                # later arrivals fold into their region's `other`
-                # sentinel row, so the table/carry stop growing. Folded
-                # keys stay out of the device table — their traffic
-                # keeps re-missing — but each miss lands here and folds
-                # exactly once per sample, so nothing is lost.
-                uniq, inverse = np.unique(rows.astype(np.int64), axis=0,
-                                          return_inverse=True)
-                uids = np.empty(len(uniq), np.int64)
-                for i in range(len(uniq)):
-                    key = tuple(int(v) for v in uniq[i])
-                    cid = interner.find_row(uniq[i])
-                    if cid is None:
-                        resident = len(interner) - len(other_by_region)
-                        if resident < max_combinations:
-                            cid = interner.intern(key)
-                        else:
-                            region = key[0]
-                            cid = other_by_region.get(region)
-                            if cid is None:
-                                cid = interner.intern(other_row(region, W))
-                                other_by_region[region] = cid
-                            tail_folds += int(np.sum(inverse == i))
-                    uids[i] = cid
-                cids = uids[inverse.reshape(-1)]
-            if len(interner) > cap:
-                new_cap = 1 << (len(interner) - 1).bit_length()
-                pad = new_cap - cap
-                pad_stat = (pad,) if n_chan == 1 else (pad, n_chan)
-                counts, psum, psumsq, n, prev = carry
-                carry = (jnp.concatenate([counts,
-                                          jnp.zeros(pad, counts.dtype)]),
-                         jnp.concatenate([psum,
-                                          jnp.zeros(pad_stat,
-                                                    psum.dtype)]),
-                         jnp.concatenate([psumsq,
-                                          jnp.zeros(pad_stat,
-                                                    psumsq.dtype)]),
-                         n, prev)
-                cap = new_cap
-            table, table_ids, n_rows = _build_table(interner, cap, W, pack)
-            idx = np.full(chunk_size, cap, np.int64)
-            idx[valid] = cids
-            carry = _combo_fold_jit(carry, jnp.asarray(idx), total_dev,
-                                    valid_dev)
+            with jax.profiler.TraceAnnotation("alea/miss"):
+                with jax.profiler.TraceAnnotation("alea/miss/recompute"):
+                    rid_dev, total_dev, valid_dev = _chunk_recompute_fn(
+                        chunk_size, spec, dtl.grid_k)(
+                            *dtl.arrays(), t_raw, t_end_j, prev_in)
+                    valid = np.asarray(valid_dev)
+                    rows = np.asarray(rid_dev).T[valid].astype(np.int64)
+                with jax.profiler.TraceAnnotation("alea/miss/intern"):
+                    rows_interned += len(rows)
+                    if max_combinations is None:
+                        cids = interner.encode(rows)
+                    else:
+                        cids, folds = _admit_or_fold(
+                            interner, rows, max_combinations,
+                            other_by_region, W)
+                        tail_folds += folds
+                with jax.profiler.TraceAnnotation("alea/miss/table"):
+                    if len(interner) > cap:
+                        new_cap = 1 << (len(interner) - 1).bit_length()
+                        carry = _grow_carry(carry, new_cap - cap)
+                        cap = new_cap
+                    table, table_ids, n_rows = _build_table(interner, cap,
+                                                            W, pack)
+                    table_rebuilds += 1
+                    idx = np.full(chunk_size, cap, np.int64)
+                    idx[valid] = cids
+                    carry = _combo_fold_jit(carry, jnp.asarray(idx),
+                                            total_dev, valid_dev)
         counts, psum, psumsq, n, _ = carry
         k_combos = len(interner)
         n = int(n)
@@ -922,6 +971,9 @@ def run_combo_pipeline(dtl: DeviceTimeline, spec: SensorSpec, *,
     if stats is not None:
         stats["chunks"] = k_chunks
         stats["miss_chunks"] = miss_chunks
+        stats["distinct_combinations"] = k_combos
+        stats["rows_interned"] = rows_interned
+        stats["table_rebuilds"] = table_rebuilds
         if max_combinations is not None:
             stats["tail_folds"] = tail_folds
     if n == 0:
@@ -943,7 +995,13 @@ def run_combo_pipeline(dtl: DeviceTimeline, spec: SensorSpec, *,
 
 def _ref_times(seed: int, k: int, period: float, jitter: float,
                chunk_size: int) -> np.ndarray:
-    with enable_x64():
+    """Chunk ``k``'s sample times, :func:`chunk_sample_times` run on the
+    host's CPU: IEEE float64, where the contract's nanosecond rounding is
+    exact. A device that emulates float64 (the TPU) can round a time to
+    the neighbouring nanosecond, so the combination pipeline takes its
+    times from here too."""
+    with enable_x64(), jax.default_device(jax.local_devices(
+            backend="cpu")[0]):
         t = chunk_sample_times(jax.random.PRNGKey(seed), jnp.int32(k),
                                jnp.float64(period), jnp.float64(jitter),
                                chunk_size=chunk_size)

@@ -137,8 +137,8 @@ def test_combo_chunk_step_compiles(one_chip):
                  jnp.zeros(cap, jnp.float64), jnp.zeros((), jnp.int64),
                  -jnp.ones((), jnp.float64))
         args = (carry, table, table_ids, n_rows, *dtl.arrays(),
-                jax.random.PRNGKey(0), jnp.int32(0), jnp.float64(1e-3),
-                jnp.float64(200e-6), jnp.float64(dtl.t_end))
+                jnp.asarray(dp._ref_times(0, 0, 1e-3, 200e-6, CHUNK)),
+                jnp.float64(dtl.t_end))
         step.lower(*_on(one_chip, args)).compile()
 
 

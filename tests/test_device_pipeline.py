@@ -183,7 +183,6 @@ def test_combo_pipeline_steady_state_stops_transferring():
 def test_chunk_step_carry_is_donated():
     """The donated carry contract: after a step, the previous carry's
     buffers are consumed (no second live copy of the accumulators)."""
-    import jax
     import jax.numpy as jnp
     from jax import enable_x64
     tls = _timelines(2, steps=20)
@@ -199,12 +198,165 @@ def test_chunk_step_carry_is_donated():
                  jnp.zeros(cap, jnp.float64), jnp.zeros((), jnp.int64),
                  -jnp.ones((), jnp.float64))
         new_carry, miss, *_ = step(carry, table, tids, n_rows,
-                                   *dtl.arrays(), jax.random.PRNGKey(0),
-                                   jnp.int32(0), jnp.float64(1e-2),
-                                   jnp.float64(2e-4),
+                                   *dtl.arrays(),
+                                   jnp.asarray(dp._ref_times(
+                                       0, 0, 1e-2, 2e-4, 256)),
                                    jnp.float64(dtl.t_end))
     assert all(buf.is_deleted() for buf in carry)
     assert not any(buf.is_deleted() for buf in new_carry[:3])
+
+
+def _spmd_barrier_timelines(w, steps=150, seed=0, skew=2e-4):
+    """SPMD workers with a barrier at every step: every worker runs the
+    same ops in the same order (its own op-time jitter), starts each step
+    a half-normal skew after the slowest worker's previous step ended, and
+    waits in the ``barrier`` region (id 0) until then. Worker 0 stops
+    after its last op, so the workers are ragged."""
+    rng = np.random.default_rng(seed)
+    R, n_ops = 24, 30
+    names = ("barrier",) + tuple(f"op{i}" for i in range(1, R))
+    ids = rng.integers(1, R, n_ops)
+    rec = rng.uniform(1e-4, 1e-3, n_ops)
+    durs = rec * np.clip(1 + 0.02 * rng.standard_normal((w, steps, n_ops)),
+                         0.9, 1.1)
+    off = skew * np.abs(rng.standard_normal((w, steps)))
+    busy = off + durs.sum(axis=2)
+    lead = busy.max(axis=0) - busy
+    waits = np.concatenate([off[:, :1], lead[:, :-1] + off[:, 1:],
+                            lead[:, -1:]], axis=1)
+    tls = []
+    for k in range(w):
+        rid, dur = [], []
+        for s in range(steps):
+            rid += [0, *ids]
+            dur += [waits[k, s], *durs[k, s]]
+        if k:
+            rid.append(0)
+            dur.append(waits[k, -1] + 1e-3)
+        rid, dur = np.array(rid, np.int32), np.array(dur)
+        tls.append(Timeline(rid, dur, 40.0 + 20.0 * rid + rng.random(len(rid)),
+                            names))
+    return tls
+
+
+def test_combo_pipeline_spmd_barrier_matches_reference():
+    """Ragged SPMD-barrier workers: counts equal the reference's, ids line
+    up, and the combinations the skew keeps making miss every chunk."""
+    tls = _spmd_barrier_timelines(4)
+    assert len({len(tl.region_ids) for tl in tls}) == 2
+    spec = RaplTraceSensor.make_spec()
+    dtl = dp.DeviceTimeline.from_timelines(tls)
+    stats = {}
+    agg, n = dp.run_combo_pipeline(dtl, spec, period=1e-3, jitter=2e-4,
+                                   seed=11, chunk_size=128, stats=stats)
+    ragg, rn = dp.reference_combo_pipeline(tls, lambda tl: spec,
+                                           period=1e-3, jitter=2e-4,
+                                           seed=11, chunk_size=128)
+    assert n == rn
+    assert agg.interner.combos == ragg.interner.combos
+    _assert_stats_close((agg.agg.counts, agg.agg.psum, agg.agg.psumsq),
+                        (ragg.agg.counts, ragg.agg.psum, ragg.agg.psumsq))
+
+
+@pytest.mark.parametrize("seed,k", [(0, 0), (11, 3), (2022890337, 1)])
+def test_host_clock_is_the_float64_contract(seed, k):
+    """The combination pipeline's sample times (``_ref_times``, run on the
+    host's CPU) are the contract's formula in IEEE float64 bit for bit:
+    numpy's arithmetic on the same threefry draws."""
+    import jax
+    from jax import enable_x64
+    c, period, jitter = 65536, 1e-3, 2e-4
+    with enable_x64(), jax.default_device(jax.local_devices(
+            backend="cpu")[0]):
+        root = jax.random.PRNGKey(seed)
+        u0 = float(jax.random.uniform(jax.random.fold_in(root, 0), (),
+                                      "float64", 0.0, period))
+        u = np.asarray(jax.random.uniform(jax.random.fold_in(root, k + 1),
+                                          (c,), "float64", 0.0, jitter))
+    i = np.arange(k * c, (k + 1) * c, dtype=np.int64)
+    t = u0 + i.astype(np.float64) * period + u
+    want = np.floor(t * 1e9 + 0.5) * 1e-9
+    got = dp._ref_times(seed, k, period, jitter, c)
+    assert got.dtype == np.float64
+    np.testing.assert_array_equal(got.view(np.int64), want.view(np.int64))
+
+
+def test_combo_pipeline_takes_its_times_from_the_host_clock(monkeypatch):
+    """Every chunk of a combination run, and its replay on a miss, is
+    attributed at the host clock's times for that chunk."""
+    real, drawn = dp._ref_times, []
+
+    def spy(seed, k, period, jitter, chunk_size):
+        drawn.append((seed, k, period, jitter, chunk_size))
+        return real(seed, k, period, jitter, chunk_size)
+
+    monkeypatch.setattr(dp, "_ref_times", spy)
+    tls = _spmd_barrier_timelines(4, steps=60)
+    dtl = dp.DeviceTimeline.from_timelines(tls)
+    stats = {}
+    dp.run_combo_pipeline(dtl, RaplTraceSensor.make_spec(), period=1e-3,
+                          jitter=2e-4, seed=5, chunk_size=128, stats=stats)
+    assert stats["miss_chunks"] >= 1
+    assert drawn == [(5, k, 1e-3, 2e-4, 128)
+                     for k in range(stats["chunks"])]
+
+
+def test_combo_pipeline_reports_the_miss_path():
+    """``stats`` names the host's share of the work: every chunk of the
+    SPMD-barrier run brings new rows, so every chunk misses, every miss
+    reads its valid rows back and rebuilds the table once, and the
+    distinct count is the interner's."""
+    tls = _spmd_barrier_timelines(4, seed=3)
+    dtl = dp.DeviceTimeline.from_timelines(tls)
+    stats = {}
+    agg, n = dp.run_combo_pipeline(dtl, RaplTraceSensor.make_spec(),
+                                   period=1e-3, jitter=2e-4, seed=2,
+                                   chunk_size=128, stats=stats)
+    # The chunks that hold a combination no earlier chunk held, on the
+    # host: every one of them, and no other, misses.
+    t_end = min(tl.t_exec for tl in tls)
+    seen, fresh = set(), 0
+    for k in range(stats["chunks"]):
+        t = dp._ref_times(2, k, 1e-3, 2e-4, 128)
+        rows = np.stack([tl.region_at(np.minimum(t, t_end)) for tl in tls],
+                        axis=1)[t < t_end]
+        got = set(map(tuple, rows.tolist()))
+        fresh += bool(got - seen)
+        seen |= got
+    assert stats["chunks"] >= 4
+    assert stats["miss_chunks"] == fresh == stats["chunks"]
+    assert stats["distinct_combinations"] == len(agg.interner) == len(seen)
+    assert stats["rows_interned"] == n
+    assert stats["table_rebuilds"] == stats["miss_chunks"]
+    # Phase-repeating workers reach the steady state: later chunks fold on
+    # the device, nothing is read back for them.
+    stats = {}
+    agg, n = dp.run_combo_pipeline(
+        dp.DeviceTimeline.from_timelines(_timelines(2, steps=120)),
+        InstantTraceSensor.make_spec(), period=5e-3, seed=0,
+        chunk_size=256, stats=stats)
+    assert stats["miss_chunks"] < stats["chunks"]
+    assert stats["rows_interned"] < n
+    assert stats["table_rebuilds"] == stats["miss_chunks"]
+    assert stats["distinct_combinations"] == len(agg.interner)
+
+
+def test_bounded_combo_pipeline_reports_tail_folds():
+    """In the bounded tier the miss path folds what the table cannot admit
+    into tail rows: every sample is still counted once, and each miss
+    chunk rebuilds the table once."""
+    tls = _spmd_barrier_timelines(4, seed=5)
+    dtl = dp.DeviceTimeline.from_timelines(tls)
+    stats = {}
+    agg, n = dp.run_combo_pipeline(dtl, RaplTraceSensor.make_spec(),
+                                   period=1e-3, jitter=2e-4, seed=4,
+                                   chunk_size=128, max_combinations=8,
+                                   stats=stats)
+    assert 0 < stats["tail_folds"] < n
+    assert stats["miss_chunks"] == stats["chunks"]
+    assert stats["table_rebuilds"] == stats["miss_chunks"]
+    assert stats["rows_interned"] == n == int(np.sum(agg.agg.counts))
+    assert stats["distinct_combinations"] == len(agg.interner)
 
 
 # ---------------------------------------------------------------------------
@@ -234,6 +386,41 @@ def test_region_pipeline_hlo_names_every_stage(domains):
     # Scopes nest, and the innermost names the operation: the lookups of
     # the RAPL refresh times sit inside the sensor stage.
     assert any(re.search(r"alea/sensor/.*alea/lookup", n) for n in op_names)
+
+
+def test_combo_step_hlo_names_key_and_fold():
+    """The combination step packs and searches its keys under
+    ``alea/combo_key`` and scatters under ``alea/combo_fold``; the miss
+    path's fold scatters under ``alea/combo_fold`` too."""
+    import re
+
+    import jax.numpy as jnp
+    from jax import enable_x64
+    tls = _spmd_barrier_timelines(16, steps=4)
+    dtl = dp.DeviceTimeline.from_timelines(tls)
+    spec = RaplTraceSensor.make_spec()
+    pack = dp._pack_spec(dtl.num_regions, 16)
+    cap = dp._TABLE_MIN
+    with enable_x64():
+        step = dp._combo_step_fn(256, spec, dtl.grid_k, pack)
+        table, tids, n_rows = dp._build_table(dp.CombinationInterner(),
+                                              cap, 16, pack)
+        carry = (jnp.zeros(cap, jnp.int64), jnp.zeros(cap, jnp.float64),
+                 jnp.zeros(cap, jnp.float64), jnp.zeros((), jnp.int64),
+                 -jnp.ones((), jnp.float64))
+        hlo = step.lower(carry, table, tids, n_rows, *dtl.arrays(),
+                         jnp.asarray(dp._ref_times(0, 0, 1e-3, 2e-4, 256)),
+                         jnp.float64(dtl.t_end)).as_text(
+                             dialect="hlo", debug_info=True)
+        fold = dp._combo_fold_jit.lower(
+            carry, jnp.zeros(256, jnp.int64), jnp.zeros(256, jnp.float64),
+            jnp.ones(256, bool)).as_text(dialect="hlo", debug_info=True)
+    op_names = re.findall(r'op_name="([^"]*)"', hlo)
+    scopes = {m for n in op_names for m in re.findall(r"alea/(\w+)", n)}
+    assert {"combo_key", "combo_fold", "lookup", "sensor"} <= scopes
+    assert any("scatter" in line and "alea/combo_fold" in line
+               for line in hlo.splitlines())
+    assert re.search(r'op_name="[^"]*alea/combo_fold', fold)
 
 
 # ---------------------------------------------------------------------------
